@@ -22,14 +22,16 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.kernels import autotune as _at
 from repro.kernels import ops, ref
 from repro.kernels import pairwise_l2 as _pw
 from repro.kernels import topk_l2 as _tk
 
 from .common import emit, env_caps, radius_for, timed, write_bench_json
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+# the analytic terms are v5e terms, read from the autotuner's chip table
+PEAK_FLOPS = _at.CHIPS["TPU v5 lite"]["peak_flops"]
+HBM_BW = _at.CHIPS["TPU v5 lite"]["hbm_bw"]
 
 
 def _capped(m: int, n: int):
@@ -171,8 +173,6 @@ def run(full: bool = False):
         )
 
     # ---- autotuner: analytic choice, then measured refinement ----------
-    from repro.kernels import autotune as _at
-
     mm, nn = _capped(256, 4096)
     dd, kk = 64, 8
     qa = jnp.asarray(rng.standard_normal((mm, dd)), jnp.float32)

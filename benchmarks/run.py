@@ -24,6 +24,10 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.configure()
+
     from . import (
         constrained_nn,
         depth,

@@ -20,6 +20,8 @@ the O(n_nodes) node table.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from typing import Dict, Tuple
 
@@ -141,20 +143,75 @@ def _fscan_threshold(t, seg, weights, cnt, num_segs, spec: TreeSpec):
     return t_c, rng
 
 
+@functools.partial(jax.jit, static_argnames=("num_segs", "spec_fields"))
+def _level(x, point_node, frozen, *, num_segs: int, spec_fields: tuple):
+    """Split every node of one level in one compiled program: returns the
+    level's per-node stats and the descended (point_node, frozen).
+    `spec_fields` is the TreeSpec as a hashable tuple (a jit static)."""
+    spec = TreeSpec(*spec_fields)
+    d = x.shape[1]
+    seg = point_node - (num_segs - 1)
+    in_level = ~frozen & (seg >= 0) & (seg < num_segs)
+    seg = jnp.where(in_level, seg, 0)
+
+    cnt, mean, radius = _segment_stats(x, seg, in_level, num_segs)
+    exists = cnt > 0
+
+    # --- choose axis ---------------------------------------------------
+    xc = jnp.where(in_level[:, None], x - mean[seg], 0.0)
+    if spec.splitter == "ballstar":
+        axis = _pca_direction(xc, seg, in_level, num_segs, d, spec.power_iters)
+        t = (xc * axis[seg]).sum(-1)
+    elif spec.splitter == "ball":
+        axis, t_pivotmid = _ball_axis(x, seg, in_level, mean, num_segs)
+        t = (x * axis[seg]).sum(-1)
+    elif spec.splitter == "kd":
+        axis = _kd_axis(x, seg, in_level, num_segs, d)
+        t = (x * axis[seg]).sum(-1)
+    else:
+        raise ValueError(spec.splitter)
+
+    # --- choose threshold ----------------------------------------------
+    t_c, t_range = _fscan_threshold(t, seg, in_level, cnt, num_segs, spec)
+    if spec.splitter == "ball":
+        t_c = t_pivotmid
+
+    splittable = exists & (cnt > spec.leaf_size) & (t_range > 1e-7)
+
+    # fscan candidates always leave both sides non-empty when range>0;
+    # the pivot-midpoint cut can not (pivots are extreme points). Guard
+    # anyway: degenerate splits freeze the node as a leaf.
+    right = (t < t_c[seg]) & in_level & splittable[seg]
+    n_right = jax.ops.segment_sum(
+        right.astype(jnp.int32), seg, num_segments=num_segs
+    )
+    ok = splittable & (n_right > 0) & (n_right < cnt)
+
+    # --- descend ---------------------------------------------------------
+    do_split = ok[seg] & in_level
+    child = 2 * point_node + 1 + right.astype(jnp.int32)
+    point_node = jnp.where(do_split, child, point_node)
+    frozen = frozen | (in_level & ~do_split)
+    return cnt, mean, radius, exists, ok, point_node, frozen
+
+
 def build(points: np.ndarray, spec: TreeSpec | None = None) -> Tree:
     """Vectorized construction. Returns the same `Tree` layout as
     `build_host.build` (numpy arrays, ready for `search_jax.device_tree`)."""
     spec = spec or TreeSpec()
-    x = jnp.asarray(np.asarray(points), jnp.float32)
-    n, d = x.shape
+    pts = np.asarray(points, np.float32)
+    n, d = pts.shape
     max_levels = max(1, int(math.ceil(math.log2(max(2, n)))) + 2)
-
-    point_node = jnp.zeros(n, dtype=jnp.int32)  # implicit complete-tree id
-    frozen = jnp.zeros(n, dtype=bool)
+    # rows padded to a power of two and frozen from the start: they never
+    # join a level, so every build of the same pow2 size class reuses one
+    # compiled program per level (seals and merges hit the jit cache)
+    n_pad = 1 << max(0, (n - 1).bit_length())
+    x = jnp.asarray(np.pad(pts, ((0, n_pad - n), (0, 0))))
+    point_node = jnp.zeros(n_pad, dtype=jnp.int32)  # implicit complete-tree id
+    frozen = jnp.asarray(np.arange(n_pad) >= n)
 
     # node table accumulated on host: implicit_id -> (center, radius, count,
-    # is_leaf). Levels are processed eagerly; each level is one fused jnp
-    # pass (jit-compiled by XLA on first use of each (level-size) shape).
+    # is_leaf). Each level is one compiled `_level` program.
     node_center: Dict[int, np.ndarray] = {}
     node_radius: Dict[int, float] = {}
     node_count: Dict[int, int] = {}
@@ -162,45 +219,10 @@ def build(points: np.ndarray, spec: TreeSpec | None = None) -> Tree:
 
     for level in range(max_levels):
         base = (1 << level) - 1
-        num_segs = 1 << level
-        seg = point_node - base
-        in_level = ~frozen & (seg >= 0) & (seg < num_segs)
-        seg = jnp.where(in_level, seg, 0)
-
-        cnt, mean, radius = _segment_stats(x, seg, in_level, num_segs)
-        exists = cnt > 0
-
-        # --- choose axis ---------------------------------------------------
-        xc = jnp.where(in_level[:, None], x - mean[seg], 0.0)
-        if spec.splitter == "ballstar":
-            axis = _pca_direction(
-                xc, seg, in_level, num_segs, d, spec.power_iters
-            )
-            t = (xc * axis[seg]).sum(-1)
-        elif spec.splitter == "ball":
-            axis, t_pivotmid = _ball_axis(x, seg, in_level, mean, num_segs)
-            t = (x * axis[seg]).sum(-1)
-        elif spec.splitter == "kd":
-            axis = _kd_axis(x, seg, in_level, num_segs, d)
-            t = (x * axis[seg]).sum(-1)
-        else:
-            raise ValueError(spec.splitter)
-
-        # --- choose threshold ----------------------------------------------
-        t_c, t_range = _fscan_threshold(t, seg, in_level, cnt, num_segs, spec)
-        if spec.splitter == "ball":
-            t_c = t_pivotmid
-
-        splittable = exists & (cnt > spec.leaf_size) & (t_range > 1e-7)
-
-        # fscan candidates always leave both sides non-empty when range>0;
-        # the pivot-midpoint cut can not (pivots are extreme points). Guard
-        # anyway: degenerate splits freeze the node as a leaf.
-        right = (t < t_c[seg]) & in_level & splittable[seg]
-        n_right = jax.ops.segment_sum(
-            right.astype(jnp.int32), seg, num_segments=num_segs
+        cnt, mean, radius, exists, ok, point_node, frozen = _level(
+            x, point_node, frozen, num_segs=1 << level,
+            spec_fields=dataclasses.astuple(spec),
         )
-        ok = splittable & (n_right > 0) & (n_right < cnt)
 
         # --- record this level's nodes (host) -------------------------------
         cnt_h = np.asarray(cnt, dtype=np.int64)
@@ -217,12 +239,6 @@ def build(points: np.ndarray, spec: TreeSpec | None = None) -> Tree:
 
         if not ok_h.any():
             break
-
-        # --- descend ---------------------------------------------------------
-        do_split = ok[seg] & in_level
-        child = 2 * point_node + 1 + right.astype(jnp.int32)
-        point_node = jnp.where(do_split, child, point_node)
-        frozen = frozen | (in_level & ~do_split)
 
     # any node never split at loop end is a leaf (already marked)
 
@@ -242,7 +258,7 @@ def build(points: np.ndarray, spec: TreeSpec | None = None) -> Tree:
 
     # point ordering: sort by the leaf's slot interval in the complete tree
     # so every node's points are contiguous and nested.
-    pn = np.asarray(point_node)
+    pn = np.asarray(point_node)[:n]
     max_level_of = np.asarray(
         [int(math.floor(math.log2(i + 1))) for i in implicit_ids]
     )
@@ -251,7 +267,7 @@ def build(points: np.ndarray, spec: TreeSpec | None = None) -> Tree:
     local = pn + 1 - (1 << level_of_leaf)
     slot = local << (deepest - level_of_leaf)
     order = np.argsort(slot, kind="stable").astype(np.int64)
-    reordered = np.asarray(x)[order]
+    reordered = pts[order]
 
     # starts: parent-before-children pass over implicit ids (sorted order
     # guarantees parents precede children).

@@ -25,21 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-# independently of the move, so detect it from the signature
-import inspect as _inspect
-
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
-
 from repro.query import merge as qmerge
 
 from . import build_jax, search_jax as sj
@@ -143,11 +128,11 @@ def constrained_knn(
     )
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(tree_specs, P(), P(axis)),
         out_specs=(P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     def search(dt, qs, off):
         # shard-local tree: drop the leading (length-1) shard dim
@@ -200,11 +185,11 @@ def brute_constrained_knn(
     q = jnp.asarray(queries, jnp.float32)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
         out_specs=(P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     def scan(p_local, g_local, qs):
         res = sj.brute_topk(p_local, qs, k, r, gids=g_local)

@@ -13,12 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
-try:  # scipy is available in this sandbox; guard anyway
-    from scipy.stats import qmc
-
-    _HAVE_QMC = True
-except Exception:  # pragma: no cover
-    _HAVE_QMC = False
+from scipy.stats import qmc
 
 
 def latin_center(n: int, d: int = 2, seed: int = 0) -> np.ndarray:
@@ -33,10 +28,7 @@ def latin_center(n: int, d: int = 2, seed: int = 0) -> np.ndarray:
 
 def sobol(n: int, d: int = 2, seed: int = 0) -> np.ndarray:
     """Sobol low-discrepancy sequence [1]."""
-    if _HAVE_QMC:
-        eng = qmc.Sobol(d=d, scramble=True, seed=seed)
-        return eng.random(n)
-    return _van_der_corput_grid(n, d)  # pragma: no cover
+    return qmc.Sobol(d=d, scramble=True, seed=seed).random(n)
 
 
 def niederreiter(n: int, d: int = 2, seed: int = 0) -> np.ndarray:
@@ -46,10 +38,7 @@ def niederreiter(n: int, d: int = 2, seed: int = 0) -> np.ndarray:
     member of the same low-discrepancy family with very similar spatial
     statistics — as the offline stand-in (noted in EXPERIMENTS.md).
     """
-    if _HAVE_QMC:
-        eng = qmc.Halton(d=d, scramble=True, seed=seed)
-        return eng.random(n)
-    return _van_der_corput_grid(n, d)  # pragma: no cover
+    return qmc.Halton(d=d, scramble=True, seed=seed).random(n)
 
 
 def highleyman(n: int, d: int = 2, seed: int = 0) -> np.ndarray:
@@ -137,22 +126,6 @@ def road_like(n: int, d: int = 4, seed: int = 0) -> np.ndarray:
     if d != 4:
         out = out[:, :d]
     return rng.permutation(out, axis=0)
-
-
-def _van_der_corput_grid(n: int, d: int) -> np.ndarray:  # pragma: no cover
-    """Fallback quasi-uniform grid when scipy.qmc is unavailable."""
-    primes = [2, 3, 5, 7, 11, 13, 17, 19][:d]
-
-    def vdc(i, base):
-        f, r = 1.0, 0.0
-        while i > 0:
-            f /= base
-            r += f * (i % base)
-            i //= base
-        return r
-    return np.array(
-        [[vdc(i + 1, b) for b in primes] for i in range(n)]
-    )
 
 
 SYNTHETIC = {

@@ -60,7 +60,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.core.distributed import _SHARD_MAP_KW, _shard_map
 from repro.query import merge as qmerge
 from repro.query.spec import QuerySpec
 
@@ -331,12 +330,23 @@ class ShardedStreamingIndex:
             sub.start_background_compaction(interval)
 
     def stop_background_compaction(self) -> None:
-        for sub in self._shards:
-            sub.stop_background_compaction()
+        self._on_every_shard("stop_background_compaction")
 
     def close(self) -> None:
+        self._on_every_shard("close")
+
+    def _on_every_shard(self, method: str) -> None:
+        """Call `method` on every shard even when one raises (a shard
+        re-raises its background compaction failure), then re-raise
+        the first failure."""
+        errors = []
         for sub in self._shards:
-            sub.close()
+            try:
+                getattr(sub, method)()
+            except Exception as e:
+                errors.append(e)
+        if errors:
+            raise errors[0]
 
     # -- read path -----------------------------------------------------------
     def snapshot(self) -> ShardedSnapshot:
@@ -457,12 +467,12 @@ class ShardedStreamingIndex:
                 [(all_d[s, 0], all_g[s, 0]) for s in range(S)], k
             )
 
-        fold = _shard_map(
+        fold = jax.shard_map(
             _local,
             mesh=mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=(P(), P()),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )
         return jax.jit(fold)
 

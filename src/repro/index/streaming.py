@@ -137,6 +137,7 @@ class StreamingIndex:
         self._write_lock = threading.RLock()
         self._bg_thread: Optional[threading.Thread] = None
         self._bg_stop = threading.Event()
+        self._bg_error: Optional[BaseException] = None
         # opaque tag mixed into the query engine's stacked-batch cache
         # key via Snapshot.cache_tag: distinct indexes sharing a shape
         # class (serving shards) get distinct cache buckets instead of
@@ -167,6 +168,9 @@ class StreamingIndex:
         self._c_wal_records = reg.counter("index.wal_records", **lbl)
         self._c_wal_replayed = reg.counter("index.wal_replayed", **lbl)
         self._c_maintenance = reg.counter("index.maintenance_runs", **lbl)
+        self._c_bg_failures = reg.counter(
+            "index.background_compaction_failures", **lbl
+        )
         self._c_checkpoints = reg.counter("wal.checkpoints", **lbl)
         self._c_ckpt_loads = reg.counter("wal.checkpoint_loads", **lbl)
         self._c_wal_truncated = reg.counter("wal.records_truncated", **lbl)
@@ -292,6 +296,7 @@ class StreamingIndex:
             "wal_records": self._c_wal_records.value,
             "checkpoints": self._c_checkpoints.value,
             "maintenance_runs": self._c_maintenance.value,
+            "background_compaction_failures": self._c_bg_failures.value,
             "tombstone_garbage_ratio": (
                 n_dead / n_total if n_total else 0.0
             ),
@@ -502,8 +507,13 @@ class StreamingIndex:
             while not self._bg_stop.is_set():
                 try:
                     changed = self.maintain()
-                except Exception:
-                    changed = False  # log was recovered; retry later
+                except Exception as e:
+                    # the log was recovered, so the loop retries later;
+                    # the failure is counted and kept for
+                    # stop_background_compaction() to re-raise
+                    self._c_bg_failures.inc()
+                    self._bg_error = e
+                    changed = False
                 if not changed:
                     self._bg_stop.wait(interval)
 
@@ -513,19 +523,29 @@ class StreamingIndex:
         self._bg_thread.start()
 
     def stop_background_compaction(self) -> None:
+        """Stop the compaction thread. Re-raises the last exception the
+        thread caught (each one is also counted on
+        ``index.background_compaction_failures``), so no failure of
+        background work goes unseen."""
         if self._bg_thread is None:
             return
         self._bg_stop.set()
         self._bg_thread.join()
         self._bg_thread = None
+        err, self._bg_error = self._bg_error, None
+        if err is not None:
+            raise err
 
     def close(self) -> None:
         """Stop the background thread and close the WAL file handle.
-        The index itself stays usable for reads."""
-        self.stop_background_compaction()
-        if self._wal is not None:
-            self._wal.close()
-            self._wal = None
+        The index itself stays usable for reads. A background failure
+        re-raised by the stop still closes the log first."""
+        try:
+            self.stop_background_compaction()
+        finally:
+            if self._wal is not None:
+                self._wal.close()
+                self._wal = None
 
     # -- checkpointing -------------------------------------------------------
     def checkpoint(self) -> bool:

@@ -7,13 +7,14 @@ plans are resolved through each kernel's `block_plan()` (the single
 source of truth for clamp logic and analytic cost) and ranked by a
 roofline objective over the BLOCK-DEPENDENT terms —
 
-    score = max(padded_flops / PEAK_FLOPS, stream_bytes / HBM_BW)
-            + blocks * LAUNCH_OVERHEAD_S
+    score = max(padded_flops / peak_flops, stream_bytes / hbm_bw)
+            + blocks * launch_overhead_s
 
 i.e. padding waste, pipeline refetch traffic, and per-block launch
 overhead; plans whose VMEM working set cannot double-buffer inside the
-budget are rejected outright. Winners are cached per
-(kernel, shape-class, k, dtype, backend), where the shape class is the
+budget are rejected outright. The envelope comes from `CHIPS`, one row
+per `device_kind`. Winners are cached per
+(kernel, shape-class, k, dtype, device kind), where the shape class is the
 same pow2 bucketing the query engine pads to — so a shape class
 resolves to ONE stable plan and jit never recompiles for block-size
 churn.
@@ -38,11 +39,23 @@ from repro import obs
 from . import pairwise_l2 as _pw
 from . import topk_l2 as _tk
 
-# v5e-ish single-chip envelope; shared with benchmarks/kernels_bench.py
-PEAK_FLOPS = 197e12      # f32-ish FLOP/s
-HBM_BW = 819e9           # bytes/s
-LAUNCH_OVERHEAD_S = 2e-6 # per grid block: issue + pipeline ramp
-VMEM_BUDGET = 8 * 2**20  # single-buffer working set; x2 for double buffer
+# Single-chip envelope of the analytic ranking, one row per
+# `jax.Device.device_kind`. "TPU v5 lite" is the TPU v5e: 197 TFLOP/s
+# bf16 and 819 GB/s HBM (Google Cloud documentation, "TPU v5e");
+# 16 MiB is Mosaic's default scoped-VMEM limit on v5e (JAX Pallas TPU
+# docs), halved here because every block is double-buffered. The
+# per-block launch overhead is a modelling estimate, not a measurement.
+CHIPS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,         # FLOP/s
+        "hbm_bw": 819e9,              # bytes/s
+        "launch_overhead_s": 2e-6,    # per grid block: issue + ramp
+        "vmem_budget": 8 * 2**20,     # single-buffer working set
+    },
+}
+# interpret mode on the CPU runs the program the TPU compiles, so it
+# ranks blocks for the v5e
+CPU_CHIP = "TPU v5 lite"
 
 # candidate pow2 block sizes per kernel (resolved through block_plan,
 # which clamps them to the problem shape, so oversize entries are safe)
@@ -135,19 +148,54 @@ def parse_block_plan_env(
     return bm, bn, bk
 
 
-def score(plan: dict) -> float:
-    """Analytic roofline time of one launch under `plan` (seconds):
-    compute/memory envelope of the padded work + per-block overhead."""
-    t_comp = plan["padded_flops"] / PEAK_FLOPS
-    t_mem = plan["stream_bytes"] / HBM_BW
-    return max(t_comp, t_mem) + plan["blocks"] * LAUNCH_OVERHEAD_S
+def device_kind() -> str:
+    """The `CHIPS` row this process ranks for: the first device's kind
+    on a TPU, the v5e row on the CPU (interpret mode). Any other
+    backend raises — there is no generic envelope."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_CHIP
+    if dev.platform == "tpu":
+        return dev.device_kind
+    raise RuntimeError(f"no kernel envelope for platform {dev.platform!r}")
+
+
+def chip(kind: Optional[str] = None) -> dict:
+    """The envelope row for `kind` (default: `device_kind()`). An
+    unknown kind raises: ranking blocks against another chip's peaks
+    would be silently wrong."""
+    kind = device_kind() if kind is None else kind
+    if kind not in CHIPS:
+        raise ValueError(
+            f"no envelope for device kind {kind!r}; add a sourced row "
+            f"to autotune.CHIPS (known: {sorted(CHIPS)})"
+        )
+    return CHIPS[kind]
+
+
+def score(plan: dict, env: dict) -> float:
+    """Analytic roofline time of one launch under `plan` (seconds) on
+    the chip `env`: compute/memory envelope of the padded work +
+    per-block overhead."""
+    t_comp = plan["padded_flops"] / env["peak_flops"]
+    t_mem = plan["stream_bytes"] / env["hbm_bw"]
+    return max(t_comp, t_mem) + plan["blocks"] * env["launch_overhead_s"]
 
 
 def _rank(
-    kernel: str, m: int, n: int, d: int, k: int, itemsize: int = 4
+    kernel: str,
+    m: int,
+    n: int,
+    d: int,
+    k: int,
+    itemsize: int = 4,
+    env: Optional[dict] = None,
 ) -> list[dict]:
     """All candidate plans for the shape, deduped post-clamp, feasible
     VMEM only, cheapest analytic score first."""
+    env = chip() if env is None else env
     planner = _PLANNERS[kernel]
     cand = _CANDIDATES[kernel]
     seen, plans = set(), []
@@ -161,9 +209,9 @@ def _rank(
                 if key in seen:
                     continue
                 seen.add(key)
-                if 2 * p["vmem_bytes"] > VMEM_BUDGET:
+                if 2 * p["vmem_bytes"] > env["vmem_budget"]:
                     continue
-                p["score"] = score(p)
+                p["score"] = score(p, env)
                 plans.append(p)
     plans.sort(key=lambda p: p["score"])
     return plans
@@ -192,27 +240,26 @@ def choose_plan(
     k: int = 0,
     *,
     dtype: str = "float32",
-    backend: Optional[str] = None,
+    kind: Optional[str] = None,
     measure: Optional[Callable[[dict], float]] = None,
     trials: int = 3,
 ) -> dict:
     """The (cached) block plan for one kernel launch shape.
 
-    Cache key: (kernel, pow2 shape class, k, dtype, backend) — every
-    shape in a class gets the same plan, so the jit caches keyed on
+    Cache key: (kernel, pow2 shape class, k, dtype, device kind) —
+    `kind` defaults to `device_kind()`; every shape in a class gets the
+    same plan, so the jit caches keyed on
     (shape, blocks) stay warm. `REPRO_BLOCK_PLAN` short-circuits the
     ranking entirely (source="env"). Passing `measure` (a callable
     running one launch under a candidate plan, returning seconds)
     re-ranks the top `trials` analytic candidates by wall clock and
     keeps the fastest (source="measured") — only benchmarks do this.
     """
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
+    kind = device_kind() if kind is None else kind
+    env = chip(kind)
     pinned = parse_block_plan_env()
     cls = shape_class(m, n, d)
-    key = (kernel, cls, k, dtype, backend, pinned, measure is not None)
+    key = (kernel, cls, k, dtype, kind, pinned, measure is not None)
     hit = _CACHE.get(key)
     if hit is not None:
         return hit
@@ -224,10 +271,10 @@ def choose_plan(
         plan = _PLANNERS[kernel](
             m, n, d, k, bm=bm, bn=bn, bk=bk, itemsize=itemsize
         )
-        plan["score"] = score(plan)
+        plan["score"] = score(plan, env)
         plan["source"] = "env"
     else:
-        ranked = _rank(kernel, m, n, d, k, itemsize)
+        ranked = _rank(kernel, m, n, d, k, itemsize, env)
         plan = ranked[0]
         plan["source"] = "analytic"
         if measure is not None:
@@ -247,8 +294,8 @@ def decisions() -> dict:
     """Every cached decision of this process, keyed for the
     `BENCH_obs.json` `autotune` section."""
     out = {}
-    for (kernel, cls, k, dtype, backend, _pin, _meas), plan in _CACHE.items():
-        key = f"{kernel}/{'x'.join(map(str, cls))}/k{k}/{dtype}/{backend}"
+    for (kernel, cls, k, dtype, kind, _pin, _meas), plan in _CACHE.items():
+        key = f"{kernel}/{'x'.join(map(str, cls))}/k{k}/{dtype}/{kind}"
         out[key] = {
             "bm": plan["bm"],
             "bn": plan["bn"],

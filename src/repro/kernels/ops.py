@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; on CPU (this sandbox) they run in
-interpret mode, which executes the kernel body in Python — bit-for-bit
-the same program the TPU would trace. `interpret` is auto-detected.
+On TPU the kernels run compiled; on the CPU (the test suite) they run
+in interpret mode, which executes the kernel body in Python — the same
+program the TPU compiles. Any other backend is an error: there is no
+silent interpret fallback on an accelerator.
 """
 from __future__ import annotations
 
@@ -17,7 +18,15 @@ from . import topk_l2 as _tk
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"backend {backend!r} is neither"
+    )
 
 
 def _tuned(

@@ -56,11 +56,11 @@ rounding as the traversal's in-loop leaf evaluation, which the
 two-phase path must match bit-for-bit — and the slot tie-break key
 reproduces the traversal's insertion order exactly.
 
-All comparator stages address XOR partners by reshaping the lane axis
-to (pairs, 2, stride) and comparing along the pair axis — static
-reshapes and selects only, no gathers, scatters, or dynamic indexing
-(and an order of magnitude cheaper for XLA to compile than the
-equivalent roll-based partner addressing).
+All comparator stages address XOR partners with two static lane
+rotations (`pltpu.roll`) and a lane-iota select, and take sort
+directions from int32 lane arithmetic — the forms Mosaic lowers. No
+gathers, scatters, dynamic indexing, or lane-axis reshapes (Mosaic
+refuses to split the lane axis into (pairs, 2, stride)).
 """
 from __future__ import annotations
 
@@ -173,39 +173,44 @@ def block_plan(
     }
 
 
-def _asc_groups(width: int, stride: int, size: int, invert: bool):
-    """Per-pair-group sort direction for a compare-exchange at
-    `stride` during bitonic stage `size`: lane i sorts ascending iff
-    (i & size) == 0, which for size >= 2*stride depends only on the
-    pair-group index a = i // (2*stride). Returns a (1, m, 1) mask."""
-    m = width // (2 * stride)
-    a = jax.lax.broadcasted_iota(jnp.int32, (1, m, 1), 1)
-    asc = (a & (size // (2 * stride))) == 0
-    return asc != invert
-
-
-def _cmpx(d, g, s, stride: int, asc):
+def _cmpx(d, g, s, stride: int, size: int = 0, desc: bool = False):
     """One compare-exchange stage at XOR distance `stride` along the
-    lane axis: element i pairs with i ^ stride, i.e. the lane axis
-    reshaped to (pairs, 2, stride) pairs along the middle axis. The
-    pair ends up (min, max) by the lexicographic (distance, slot) key
-    where `asc` holds, (max, min) where it doesn't. `asc` is a scalar
-    bool or a (1, pairs, 1) group mask, so one function serves sort
-    stages (direction alternates by index bit) and merge stages (one
-    direction) alike."""
+    lane axis: lane i pairs with lane i ^ stride. The lower lane of a
+    pair (bit `stride` clear) reads its partner `stride` lanes up, the
+    upper lane reads it `stride` lanes down — two lane rotations and a
+    lane-iota select, which is what Mosaic lowers natively (no
+    sub-vreg reshapes, gathers or dynamic indexing).
+
+    Each pair ends up (min, max) by the lexicographic (distance, slot)
+    key in an ascending group, (max, min) in a descending one. With
+    `size` set, lane i's group is ascending iff (i & size) == 0 — a
+    bitonic sort stage — and `desc` flips every group; with size=0 the
+    whole row shares the direction `desc` picks (merge stages).
+    Directions are int32 lane arithmetic, never bool compares. Equal
+    keys never swap, so both lanes of a pair always agree."""
     bm_, width = d.shape
-    m = width // (2 * stride)
-    view = lambda x: x.reshape(bm_, m, 2, stride)
-    dd, gg, ss = view(d), view(g), view(s)
-    lod, hid = dd[:, :, 0], dd[:, :, 1]  # (bm, m, stride)
-    log_, hig = gg[:, :, 0], gg[:, :, 1]
-    los, his = ss[:, :, 0], ss[:, :, 1]
-    out_of_order = (hid < lod) | ((hid == lod) & (his < los))
-    swap = out_of_order != ~asc  # descending groups: swap when in-order
-    pair = lambda a, b: jnp.stack(
-        [jnp.where(swap, b, a), jnp.where(swap, a, b)], axis=2
-    ).reshape(bm_, width)
-    return pair(lod, hid), pair(log_, hig), pair(los, his)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm_, width), 1)
+    is_lo = (lane & stride) == 0
+    # jnp.roll semantics: roll(x, w - t)[i] = x[i + t], roll(x, t)[i] = x[i - t]
+    partner = lambda x: jnp.where(
+        is_lo,
+        pltpu.roll(x, width - stride, 1),
+        pltpu.roll(x, stride, 1),
+    )
+    pd, pg, ps = partner(d), partner(g), partner(s)
+    partner_first = (pd < d) | ((pd == d) & (ps < s))
+    self_first = (d < pd) | ((d == pd) & (s < ps))
+    # 0 where this lane keeps the pair's min, 1 where it keeps the max
+    flip = (lane // stride) & 1
+    if size:
+        flip = flip ^ ((lane // size) & 1)
+    keep_min = flip == int(desc)
+    take = (keep_min & partner_first) | (~keep_min & self_first)
+    return (
+        jnp.where(take, pd, d),
+        jnp.where(take, pg, g),
+        jnp.where(take, ps, s),
+    )
 
 
 def _block_topk_desc(d, g, s, kp: int, bn: int):
@@ -219,8 +224,7 @@ def _block_topk_desc(d, g, s, kp: int, bn: int):
     while size <= kp:
         stride = size // 2
         while stride >= 1:
-            asc = _asc_groups(bn, stride, size, invert=full_desc)
-            d, g, s = _cmpx(d, g, s, stride, asc)
+            d, g, s = _cmpx(d, g, s, stride, size, desc=full_desc)
             stride //= 2
         size *= 2
     # stage B: tournament — compare-exchange chunk pairs (elementwise
@@ -229,21 +233,69 @@ def _block_topk_desc(d, g, s, kp: int, bn: int):
     # loser chunks only ever pair with other losers
     span = kp
     while span < bn:
-        d, g, s = _cmpx(d, g, s, span, jnp.bool_(True))
+        d, g, s = _cmpx(d, g, s, span)
         nxt = 2 * span
         stride = kp // 2
         while stride >= 1:
             # alternate winner directions for the next round; the last
             # surviving chunk is sorted descending for the caller
-            asc = (
-                _asc_groups(bn, stride, nxt, invert=False)
-                if nxt < bn
-                else jnp.bool_(False)
-            )
-            d, g, s = _cmpx(d, g, s, stride, asc)
+            if nxt < bn:
+                d, g, s = _cmpx(d, g, s, stride, nxt)
+            else:
+                d, g, s = _cmpx(d, g, s, stride, desc=True)
             stride //= 2
         span = nxt
     return d, g, s
+
+
+# rows per pass of the selection network: one f32 sublane tile. The
+# network is unrolled per pass, so looping over row tiles keeps the
+# Mosaic program (and its compile time) independent of bm
+_SEL_ROWS = 8
+
+
+def _select_block(
+    acc_ref, g_ref, r_ref, od_ref, og_ref, os_ref, j, *, kp: int, bm: int,
+    bn: int
+):
+    """Fold the finished (bm, bn) squared-distance block into the
+    carried ascending k-best, `_SEL_ROWS` rows per loop step. `g_ref`
+    holds one gid row shared by every query (1, bn) or one per query
+    (bm, bn). No sqrt in-kernel: `r_ref` carries the conservatively
+    squared radius (`radius_sq_upper`) and the wrapper refines the k
+    survivors with the exact euclidean gate."""
+    rows_n = min(_SEL_ROWS, bm)
+    shared_gids = g_ref.shape[0] != bm
+
+    def body(t, carry):
+        rows = pl.ds(pl.multiple_of(t * rows_n, rows_n), rows_n)
+        d = jnp.maximum(acc_ref[rows, :], 0.0)
+        g = jnp.broadcast_to(
+            g_ref[...] if shared_gids else g_ref[rows, :], (rows_n, bn)
+        )
+        idx = jax.lax.broadcasted_iota(jnp.int32, (rows_n, bn), 1)
+        slot = j * bn + idx  # global slot / visit order: the tie-break
+        ok = (g >= 0) & (d <= r_ref[rows, :])  # liveness & radius gates
+        d = jnp.where(ok, d, jnp.inf)
+        s = jnp.where(ok, slot, _I32_MAX)
+
+        d, g, s = _block_topk_desc(d, g, s, kp, bn)
+
+        # carried (ascending) ++ block k-best (descending) is bitonic:
+        # one merge network re-establishes the ascending carried k-best
+        md = jnp.concatenate([od_ref[rows, :], d[:, :kp]], axis=1)
+        mg = jnp.concatenate([og_ref[rows, :], g[:, :kp]], axis=1)
+        ms = jnp.concatenate([os_ref[rows, :], s[:, :kp]], axis=1)
+        stride = kp
+        while stride >= 1:
+            md, mg, ms = _cmpx(md, mg, ms, stride)
+            stride //= 2
+        od_ref[rows, :] = md[:, :kp]
+        og_ref[rows, :] = mg[:, :kp]
+        os_ref[rows, :] = ms[:, :kp]
+        return carry
+
+    jax.lax.fori_loop(0, bm // rows_n, body, 0)
 
 
 def _kernel(
@@ -276,32 +328,10 @@ def _kernel(
     # ---- selection: only on the last K step, once per (i, j) block ------
     @pl.when(kk == k_steps - 1)
     def _select():
-        # squared-domain selection: no sqrt in-kernel; r_ref carries the
-        # conservatively-squared radius (`radius_sq_upper`), the wrapper
-        # refines the k survivors with the exact euclidean gate
-        d = jnp.maximum(acc_ref[...], 0.0)
-        g = g_ref[...]                                # (1, bn) gids
-        idx = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
-        slot = j * bn + idx  # global arena slot: the tie-break key
-        ok = (g >= 0) & (d <= r_ref[...])  # liveness & radius gates
-        d = jnp.where(ok, d, jnp.inf)
-        s = jnp.where(ok, slot, _I32_MAX)
-        gb = jnp.broadcast_to(g, (bm, bn))
-
-        d, gb, s = _block_topk_desc(d, gb, s, kp, bn)
-
-        # carried (ascending) ++ block k-best (descending) is bitonic:
-        # one merge network re-establishes the ascending carried k-best
-        md = jnp.concatenate([od_ref[...], d[:, :kp]], axis=1)
-        mg = jnp.concatenate([og_ref[...], gb[:, :kp]], axis=1)
-        ms = jnp.concatenate([os_ref[...], s[:, :kp]], axis=1)
-        stride = kp
-        while stride >= 1:
-            md, mg, ms = _cmpx(md, mg, ms, stride, jnp.bool_(True))
-            stride //= 2
-        od_ref[...] = md[:, :kp]
-        og_ref[...] = mg[:, :kp]
-        os_ref[...] = ms[:, :kp]
+        _select_block(
+            acc_ref, g_ref, r_ref, od_ref, og_ref, os_ref, j,
+            kp=kp, bm=bm, bn=bn,
+        )
 
 
 @functools.partial(
@@ -512,26 +542,10 @@ def _leaf_kernel(
     # ---- selection: squared domain, identical network to `_kernel` ------
     @pl.when(kk == k_steps - 1)
     def _select():
-        d = jnp.maximum(acc_ref[...], 0.0)
-        g = g_ref[...]  # (bm, bn) per-row candidate gids
-        idx = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
-        slot = j * bn + idx  # DFS visit-order position: the tie-break
-        ok = (g >= 0) & (d <= r_ref[...])
-        d = jnp.where(ok, d, jnp.inf)
-        s = jnp.where(ok, slot, _I32_MAX)
-
-        d, g, s = _block_topk_desc(d, g, s, kp, bn)
-
-        md = jnp.concatenate([od_ref[...], d[:, :kp]], axis=1)
-        mg = jnp.concatenate([og_ref[...], g[:, :kp]], axis=1)
-        ms = jnp.concatenate([os_ref[...], s[:, :kp]], axis=1)
-        stride = kp
-        while stride >= 1:
-            md, mg, ms = _cmpx(md, mg, ms, stride, jnp.bool_(True))
-            stride //= 2
-        od_ref[...] = md[:, :kp]
-        og_ref[...] = mg[:, :kp]
-        os_ref[...] = ms[:, :kp]
+        _select_block(
+            acc_ref, g_ref, r_ref, od_ref, og_ref, os_ref, j,
+            kp=kp, bm=bm, bn=bn,
+        )
 
 
 def _leaf_call(

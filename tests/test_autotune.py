@@ -24,7 +24,7 @@ def test_choose_plan_feasible_and_ranked():
     assert plan["source"] == "analytic"
     assert plan["bm"] % 8 == 0
     assert plan["bn"] & (plan["bn"] - 1) == 0
-    assert 2 * plan["vmem_bytes"] <= at.VMEM_BUDGET
+    assert 2 * plan["vmem_bytes"] <= at.chip()["vmem_budget"]
     # the winner scores no worse than every other feasible candidate
     ranked = at._rank("topk_l2", 512, 4096, 64, 8)
     assert plan["score"] == ranked[0]["score"]
@@ -96,7 +96,7 @@ def test_decisions_export_schema():
     dec = at.decisions()
     assert len(dec) == 2
     for key, plan in dec.items():
-        kernel, cls, kk, dtype, backend = key.split("/")
+        kernel, cls, kk, dtype, kind = key.split("/")
         assert kernel in ("topk_l2", "leaf_topk_l2")
         assert kk.startswith("k")
         for field in ("bm", "bn", "bk", "blocks"):
@@ -120,3 +120,16 @@ def test_block_plan_cost_terms_are_block_independent_vs_dependent():
     assert a["flops"] == c["flops"]  # same bn: identical flop bill
     assert a["stream_bytes"] != b["stream_bytes"]
     assert a["vmem_bytes"] != b["vmem_bytes"]
+
+
+def test_chip_table_is_keyed_by_device_kind():
+    """Interpret mode on the CPU ranks for the v5e row by name; a TPU
+    kind without a sourced row raises instead of borrowing peaks."""
+    assert at.device_kind() == at.CPU_CHIP == "TPU v5 lite"
+    assert at.chip() is at.CHIPS["TPU v5 lite"]
+    with pytest.raises(ValueError, match="no envelope"):
+        at.chip("TPU v4")
+    with pytest.raises(ValueError, match="no envelope"):
+        at.choose_plan("topk_l2", 512, 4096, 64, 8, kind="TPU v4")
+    plan = at.choose_plan("topk_l2", 512, 4096, 64, 8, kind="TPU v5 lite")
+    assert plan is at.choose_plan("topk_l2", 512, 4096, 64, 8)

@@ -250,3 +250,109 @@ def test_power_iteration_matches_eigh():
     w = ops.power_iteration(x, iters=40)
     we = first_component_exact(np.asarray(x))
     assert abs(float(np.dot(np.asarray(w), we))) > 0.999
+
+
+# -- the selection network's compare-exchange (lane rolls + iota masks) -----
+
+_CMPX_KP = [1, 2, 4, 8, 16, 32, 64, 128]
+_CMPX_BN = [128, 256, 512]
+
+
+def _cmpx_stages(d, g, s, stages):
+    """Every (stride, size, desc) stage of `topk_l2._cmpx`, each applied
+    to the same input, in one interpret-mode kernel."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import topk_l2 as tk
+
+    def kernel(d_ref, g_ref, s_ref, od_ref, og_ref, os_ref):
+        for i, (stride, size, desc) in enumerate(stages):
+            dd, gg, ss = tk._cmpx(
+                d_ref[...], g_ref[...], s_ref[...], stride, size, desc
+            )
+            od_ref[i] = dd
+            og_ref[i] = gg
+            os_ref[i] = ss
+
+    shape = (len(stages),) + d.shape
+    return pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct(shape, jnp.float32),
+            jax.ShapeDtypeStruct(shape, jnp.int32),
+            jax.ShapeDtypeStruct(shape, jnp.int32),
+        ],
+        interpret=True,
+    )(d, g, s)
+
+
+def _cmpx_ref(d, g, s, stride, size, desc):
+    """One compare-exchange stage written pair by pair: lanes i and
+    i | stride; the group is ascending iff (i & size) == 0 (always, for
+    size 0), flipped by `desc`; a pair swaps only when its keys are
+    strictly out of order, so equal keys keep their lanes."""
+    d, g, s = d.copy(), g.copy(), s.copy()
+    for i in range(d.shape[1]):
+        if i & stride:
+            continue
+        j = i | stride
+        asc = (size == 0 or (i & size) == 0) != desc
+        j_first = (d[:, j] < d[:, i]) | ((d[:, j] == d[:, i]) & (s[:, j] < s[:, i]))
+        i_first = (d[:, i] < d[:, j]) | ((d[:, i] == d[:, j]) & (s[:, i] < s[:, j]))
+        swap = j_first if asc else i_first
+        for a in (d, g, s):
+            a[swap, i], a[swap, j] = a[swap, j], a[swap, i].copy()
+    return d, g, s
+
+
+@pytest.mark.parametrize("bn", _CMPX_BN)
+@pytest.mark.parametrize("kp", _CMPX_KP)
+def test_cmpx_strides_and_directions(kp, bn):
+    """Each stride from 1 to kp, in both group directions (alternating
+    sort groups and one-direction merge stages), on tie-heavy keys:
+    lane for lane equal to the pairwise reference. The width covers the
+    carried merge (2·kp lanes) as well as the block (bn lanes)."""
+    rng = np.random.default_rng(kp * 1000 + bn)
+    w = max(bn, 2 * kp)
+    d = rng.integers(0, 3, (8, w)).astype(np.float32)
+    d[rng.random((8, w)) < 0.2] = np.inf
+    s = rng.integers(0, 4, (8, w)).astype(np.int32)
+    g = np.tile(np.arange(w, dtype=np.int32), (8, 1))
+    stages = []
+    stride = 1
+    while stride <= kp:
+        for size, desc in ((2 * stride, False), (2 * stride, True),
+                           (0, False), (0, True)):
+            stages.append((stride, size, desc))
+        stride *= 2
+    got = [np.asarray(x) for x in _cmpx_stages(d, g, s, stages)]
+    for i, stage in enumerate(stages):
+        want = _cmpx_ref(d, g, s, *stage)
+        for a, b in zip((x[i] for x in got), want):
+            assert np.array_equal(a, b), stage
+
+
+@pytest.mark.parametrize("bn", _CMPX_BN)
+@pytest.mark.parametrize("kp", _CMPX_KP)
+def test_selection_network_matches_stable_argsort(kp, bn):
+    """The whole network (chunk sort, tournament, carried merge over
+    several N blocks and two row tiles) on tie-heavy integer points
+    with dead slots: ids and distances bit-identical to a stable
+    argsort of the masked squared-distance row."""
+    rng = np.random.default_rng(kp * 7 + bn)
+    m, n = 16, 2 * bn + 37
+    q = rng.integers(0, 3, (m, 2)).astype(np.float32)
+    pts = rng.integers(0, 3, (n, 2)).astype(np.float32)
+    gids = np.arange(n, dtype=np.int32)
+    gids[rng.random(n) < 0.25] = -1
+    got_d, got_g = ops.topk_l2(
+        q, pts, jnp.asarray(gids), np.inf, kp, bm=16, bn=bn, bk=128
+    )
+    sq = ((q[:, None] - pts[None]) ** 2).sum(-1)
+    sq[:, gids < 0] = np.inf
+    order = np.argsort(sq, axis=1, kind="stable")[:, :kp]
+    want_sq = np.take_along_axis(sq, order, 1)
+    want_g = np.where(np.isinf(want_sq), -1, gids[order])
+    assert np.array_equal(np.asarray(got_g), want_g)
+    assert np.array_equal(np.asarray(got_d), np.sqrt(want_sq))

@@ -264,6 +264,42 @@ def test_stats_counter_invariants():
     check_oracle(idx, rng.standard_normal((4, 2)), k=5, r=1.5)
 
 
+def test_background_compaction_failure_counted_and_reraised(tmp_path):
+    """A failure inside the background compaction loop is counted on
+    obs and re-raised by stop_background_compaction(), not swallowed.
+    The fault is injected at the auto-checkpoint that maintain() runs
+    after a merge commits, so the merged state itself stays exact."""
+    import time
+
+    from repro.index import faults
+
+    rng = np.random.default_rng(11)
+    idx = StreamingIndex(
+        StreamingConfig(
+            dim=3, delta_capacity=16, spec=SPEC, merge_factor=2,
+            defer_merges=True, wal_path=str(tmp_path / "idx.wal"),
+        )
+    )
+    idx.add(rng.standard_normal((80, 3)))  # 5 seals, merges deferred
+    with faults.active():
+        faults.arm("checkpoint.step", times=1, exc=faults.InjectedCrash)
+        idx.start_background_compaction(interval=0.01)
+        deadline = time.monotonic() + 60.0
+        while idx.stats()["background_compaction_failures"] == 0:
+            if time.monotonic() > deadline:
+                idx.stop_background_compaction()
+                pytest.fail("the injected checkpoint fault never fired")
+            time.sleep(0.01)
+        with pytest.raises(faults.InjectedCrash):
+            idx.stop_background_compaction()
+    assert idx.stats()["background_compaction_failures"] == 1
+    idx.stop_background_compaction()  # reported once, not again
+    while idx.maintain():
+        pass
+    check_oracle(idx, rng.standard_normal((5, 3)), k=4, r=1.0)
+    idx.close()
+
+
 def test_datastore_add_delete_lookup():
     rng = np.random.default_rng(5)
     keys = rng.standard_normal((200, 4)).astype(np.float32)
