@@ -20,8 +20,8 @@ diagonal, k=8) goes through the normal entry points:
 
 Four chips (``--chips 4``): only the sharded phase — a
 `ShardedStreamingIndex` with 4 shards over a real 4-device ``data``
-mesh, strict failover, fed the same loads, inserts (10,000) and deletes
-as a single-device `StreamingIndex`; after `flush()` the two answer
+mesh, strict failover, fed the same load (100,000 points), inserts
+(10,000) and deletes as a single-device `StreamingIndex`; after `flush()` the two answer
 bit-identically and both match the brute oracle. Each shard's arrays
 must sit on their own device, and no shard may fail over.
 
@@ -399,9 +399,12 @@ def four_chips(sz: Sizes) -> None:
         data_mesh,
     )
 
-    # four chips cost four times as much per second: the same load, a
-    # fifth of the inserts (still several arena fills per shard)
-    sz = dataclasses.replace(sz, n_queries=sz.n_fresh, n_insert=10_000)
+    # four chips cost four times as much per second: a quarter of the
+    # load and a fifth of the inserts (still several arena fills per
+    # shard), the same shapes and mix
+    sz = dataclasses.replace(
+        sz, n_points=100_000, n_queries=sz.n_fresh, n_insert=10_000
+    )
     pts, queries, r = make_data(sz)
     say(f"data: 3d-road-network n={len(pts)} queries={len(queries)} "
         f"r={r!r} k={sz.k}")
