@@ -558,6 +558,14 @@ def _rescore_topk(dts: DeviceTree, frontier, queries, r, gq, slots, k: int):
     return dd, gg, skey
 
 
+@jax.jit
+def _frontier_width(nf):
+    """[max, sum] of the phase-1 frontier sizes, in one transfer: the
+    widest frontier sets phase 2's pow2 width class, the sum counts the
+    real leaves phase 2 gathers."""
+    return jnp.stack([jnp.max(nf), jnp.sum(nf)])
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def _merge_segments(dd, gg, k: int):
     """Fold the S per-segment sorted k-bests — same merge the classic
@@ -648,80 +656,108 @@ def constrained_knn_stacked_fused(
     Returns None when some query's frontier overflowed `frontier_cap`
     (the recorded list would be truncated): the caller falls back to
     the classic path, which is exact at any frontier size.
+
+    Each step runs in its own `obs.span` (`engine.phase1`,
+    `engine.frontier_sync`, `engine.phase2`, `engine.certificate`,
+    `engine.f32_rerun`, `engine.segment_merge`), and
+    `engine.phase2_slots{kind=frontier|evaluated}` counts the real
+    frontier leaves against the padded leaf slots phase 2 evaluates.
     """
     from repro import obs  # lazy: keep core import-light
     from repro.kernels import ops  # lazy: ops pulls in the obs registry
 
     if frontier_cap is None:
         frontier_cap = frontier_cap_default()
-    frontier, nf, v, lv, pe = _collect_frontier_stacked(
-        dts, queries, r, k, stack_size, frontier_cap
-    )
-    nf_max = int(jax.device_get(jnp.max(nf))) if nf.size else 0
+    # every statement below that launches device work or waits for it
+    # sits in exactly one child span of the caller's `engine.dispatch`
+    with obs.span("engine.phase1"):
+        frontier, nf, v, lv, pe = _collect_frontier_stacked(
+            dts, queries, r, k, stack_size, frontier_cap
+        )
+    with obs.span("engine.frontier_sync"):
+        # the host waits here for phase 1: one transfer of (max, sum)
+        nf_max, nf_sum = (
+            (int(x) for x in jax.device_get(_frontier_width(nf)))
+            if nf.size
+            else (0, 0)
+        )
     if nf_max > frontier_cap:
         return None
     # shrink the gather to the smallest pow2 class that holds the
     # widest frontier: bounds phase-2 memory at log2(fcap) jit classes
     f_eff = max(1, min(_tk._next_pow2(max(nf_max, 1)), frontier_cap))
-    frontier_eff = frontier[..., :f_eff]
+    s, qn = frontier.shape[0], frontier.shape[1]
+    # phase-2 fill: real frontier leaves against the leaf slots phase 2
+    # evaluates once every row is padded to f_eff (an f32 rerun
+    # evaluates the same slots again and is not counted twice)
+    obs.REGISTRY.counter("engine.phase2_slots", kind="frontier").inc(nf_sum)
+    obs.REGISTRY.counter("engine.phase2_slots", kind="evaluated").inc(
+        s * qn * f_eff
+    )
     # pin bk to cover the whole feature dim: one k-chunk per block, so
     # the in-kernel Σ(q-c)² accumulates in a single pass — the same
     # rounding as the traversal's in-loop `((pts-q)**2).sum(-1)`. A
     # smaller autotuned bk would split the sum and break bit-parity;
     # bm/bn stay tunable (they never change the arithmetic).
     bk = _tk._round_up(max(int(queries.shape[1]), 1), 128)
-    s, qn = frontier.shape[0], frontier.shape[1]
 
-    dd = gg = None
-    if leaf_q is not None:
-        kprime = k + max(1, quant_slack_default())
-        cq, cg, csc = _gather_frontier_quantized(
-            leaf_q, dts.leaf_index, gids, frontier_eff, qscale
-        )
-        rb = jnp.broadcast_to(
-            jnp.asarray(r, jnp.float32), queries.shape[:1]
-        )
-        qrows = jnp.broadcast_to(
-            queries[None], (s, qn, queries.shape[1])
-        ).reshape(-1, queries.shape[1])
-        # widen the euclidean gate by the dequant bound so no true
-        # in-radius neighbor can fail the in-kernel quantized gate
-        rgate = jnp.broadcast_to(
-            (rb + jnp.float32(qerr))[None], (s, qn)
-        ).reshape(-1)
-        sq_q, gq, slots = ops.leaf_topk_l2_raw(
-            qrows, cq, cg, rgate, kprime, cscale=csc, bk=bk
-        )
-        dd_q, gg_q, rescored = _rescore_topk(
-            dts, frontier_eff, queries, r, gq, slots, k
-        )
-        if _quant_contained(sq_q, gq, rescored, qerr, queries.shape[1], k):
-            obs.REGISTRY.counter(
-                "quantized.rescore", result="exact"
-            ).inc()
-            dd, gg = dd_q, gg_q
-        else:
-            # slack exhausted: the certificate cannot prove the true
-            # top-k survived — re-run this dispatch on the f32 buffer
-            # (exact by construction, never truncates)
-            obs.REGISTRY.counter(
-                "quantized.rescore", result="fallback"
-            ).inc()
-    if dd is None:
+    def f32_topk(frontier_eff):
         qrows, cands, cgids, rrows = _gather_frontier(
             dts, gids, queries, r, frontier_eff
         )
-        dd, gg = ops.leaf_topk_l2(qrows, cands, cgids, rrows, k, bk=bk)
-    d, g = _merge_segments(
-        dd.reshape(s, qn, k), gg.reshape(s, qn, k), k
-    )
-    return StackedResult(
-        gids=g,
-        distances=d,
-        nodes_visited=v.sum(0),
-        leaves_visited=lv.sum(0),
-        points_examined=pe.sum(0),
-    )
+        return ops.leaf_topk_l2(qrows, cands, cgids, rrows, k, bk=bk)
+
+    with obs.span("engine.phase2"):
+        frontier_eff = frontier[..., :f_eff]
+        if leaf_q is None:
+            dd, gg = f32_topk(frontier_eff)
+        else:
+            kprime = k + max(1, quant_slack_default())
+            cq, cg, csc = _gather_frontier_quantized(
+                leaf_q, dts.leaf_index, gids, frontier_eff, qscale
+            )
+            rb = jnp.broadcast_to(
+                jnp.asarray(r, jnp.float32), queries.shape[:1]
+            )
+            qrows = jnp.broadcast_to(
+                queries[None], (s, qn, queries.shape[1])
+            ).reshape(-1, queries.shape[1])
+            # widen the euclidean gate by the dequant bound so no true
+            # in-radius neighbor can fail the in-kernel quantized gate
+            rgate = jnp.broadcast_to(
+                (rb + jnp.float32(qerr))[None], (s, qn)
+            ).reshape(-1)
+            sq_q, gq, slots = ops.leaf_topk_l2_raw(
+                qrows, cq, cg, rgate, kprime, cscale=csc, bk=bk
+            )
+            dd, gg, rescored = _rescore_topk(
+                dts, frontier_eff, queries, r, gq, slots, k
+            )
+    if leaf_q is not None:
+        with obs.span("engine.certificate"):
+            contained = _quant_contained(
+                sq_q, gq, rescored, qerr, queries.shape[1], k
+            )
+        obs.REGISTRY.counter(
+            "quantized.rescore", result="exact" if contained else "fallback"
+        ).inc()
+        if not contained:
+            # slack exhausted: the certificate cannot prove the true
+            # top-k survived — re-run this dispatch on the f32 buffer
+            # (exact by construction, never truncates)
+            with obs.span("engine.f32_rerun"):
+                dd, gg = f32_topk(frontier_eff)
+    with obs.span("engine.segment_merge"):
+        d, g = _merge_segments(
+            dd.reshape(s, qn, k), gg.reshape(s, qn, k), k
+        )
+        return StackedResult(
+            gids=g,
+            distances=d,
+            nodes_visited=v.sum(0),
+            leaves_visited=lv.sum(0),
+            points_examined=pe.sum(0),
+        )
 
 
 def brute_topk(

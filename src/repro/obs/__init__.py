@@ -12,16 +12,21 @@ accounting, stage spans, per-query paper metrics), `index/streaming.py`
 and `index/delta.py` (write-path counters, occupancy/garbage gauges),
 `kernels/ops.py` (per-call block/bytes/FLOP accounting for the roofline
 report), `serve/retrieval.py` (end-to-end latency histograms), and
-`train/loop.py` (structured twins of the log lines).
+`train/loop.py` (structured twins of the log lines). `compiles` turns
+JAX's program-build events into `jit.{trace,lower,compile}_s{fun=…}`
+histograms; importing this package installs it, once per process.
 """
-from . import export, metrics, trace
+from . import compiles, export, metrics, trace
 from .metrics import REGISTRY, Registry, reset, snapshot
 from .trace import QueryTrace, span
+
+compiles.install()
 
 __all__ = [
     "REGISTRY",
     "Registry",
     "QueryTrace",
+    "compiles",
     "export",
     "metrics",
     "reset",

@@ -42,14 +42,12 @@ def current_query_trace() -> Optional["QueryTrace"]:
     return getattr(_TLS, "query_trace", None)
 
 
-def _annotation(name: str):
-    """jax.profiler.TraceAnnotation when available (it is host-side and
-    works on every backend); harmless no-op otherwise."""
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler API unavailable
+# jax.profiler.TraceAnnotation, resolved once (it is host-side and works
+# on every backend); a harmless no-op where the profiler API is missing
+try:
+    from jax.profiler import TraceAnnotation as _annotation
+except Exception:  # pragma: no cover - profiler API unavailable
+    def _annotation(name: str):
         return contextlib.nullcontext()
 
 

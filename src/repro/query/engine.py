@@ -31,10 +31,13 @@ tombstone refreshes the affected tokens, invalidating exactly the
 classes it touched.
 
 Instrumentation lives on the process-wide observability registry
-(`repro.obs`): dispatch/signature/stack-cache counters are registry
-metrics (atomic — the old module-global ints raced under threads), each
+(`repro.obs`): dispatch and stack-cache counters are registry metrics
+(atomic — the old module-global ints raced under threads), each
 execute() stage runs in an `obs.span` (plan / stack / dispatch / delta /
-merge — host timing + XLA profile annotation), and an active
+merge — host timing + XLA profile annotation; `engine.dispatch` holds
+one child span per step of the traversal, `engine.classic` for the
+classic path and `core/search_jax` names the fused path's, and
+`engine.merge` holds `engine.readback`), and an active
 `obs.QueryTrace` additionally receives the per-query device-derived
 paper metrics (nodes visited, leaves scanned, candidates evaluated).
 `dispatch_count()` / `observed_signatures()` / `compile_stats()` /
@@ -83,14 +86,11 @@ _C_FUSED_FB = obs.REGISTRY.counter(
 )
 _C_STACK_FULL = obs.REGISTRY.counter("engine.stack_cache", kind="full_build")
 _C_STACK_INCR = obs.REGISTRY.counter("engine.stack_cache", kind="incremental")
-_G_SIGNATURES = obs.REGISTRY.gauge("engine.signatures")
-_G_STACK_CACHE = obs.REGISTRY.gauge("engine.stack_cache_entries")
 
-# distinct stacked-dispatch signatures ever issued: the registry holds
-# the cardinality gauge; the tuples themselves (returned by
-# `observed_signatures()`, used by the compile-bound tests) need a set,
-# guarded by its own lock — the old code mutated it with NO lock, so
-# racing writers could lose elements mid-rehash
+# distinct stacked-dispatch signatures ever issued (returned by
+# `observed_signatures()`, used by the compile-bound tests), guarded by
+# its own lock — the old code mutated it with NO lock, so racing
+# writers could lose elements mid-rehash
 _SIGNATURES: set = set()
 _SIG_LOCK = threading.Lock()
 
@@ -318,7 +318,6 @@ def _stacked_views(group: ClassGroup, epoch: int = 0, tag=None) -> _StackEntry:
         _STACK_CACHE[key] = entry
         while len(_STACK_CACHE) > _STACK_CACHE_MAX:
             _STACK_CACHE.popitem(last=False)
-        _G_STACK_CACHE.set(len(_STACK_CACHE))
     return entry
 
 
@@ -346,7 +345,6 @@ def _dispatch_stacked(
         _SIGNATURES.add(
             (cls, int(gids.shape[0]), int(q.shape[0]), k, str(q.dtype))
         )
-        _G_SIGNATURES.set(len(_SIGNATURES))
     with obs.span("engine.dispatch"):
         # Fused two-phase traversal (collect leaf frontier, evaluate the
         # gathered candidates with the leaf_topk_l2 kernel) is bit-exact
@@ -375,7 +373,10 @@ def _dispatch_stacked(
                 _C_FUSED.inc()
                 return res
             _C_FUSED_FB.inc()
-        return sj.constrained_knn_stacked(stacked, gids, q, rb, k, stack_size)
+        with obs.span("engine.classic"):
+            return sj.constrained_knn_stacked(
+                stacked, gids, q, rb, k, stack_size
+            )
 
 
 # -- executor ----------------------------------------------------------------
@@ -483,8 +484,9 @@ def execute(snapshot, queries, spec: QuerySpec) -> EngineResult:
         d, g = qmerge.merge_parts(parts, k)
         # materialize on the host so both execute() paths (and therefore
         # Datastore.search) honor the declared np.ndarray contract
-        g_host = np.asarray(g, np.int32)
-        d_host = np.asarray(d, np.float32)
+        with obs.span("engine.readback"):
+            g_host = np.asarray(g, np.int32)
+            d_host = np.asarray(d, np.float32)
     if want_stats:
         visits = (
             np.asarray(visits, np.int32)

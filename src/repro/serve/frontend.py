@@ -56,7 +56,6 @@ onto each `SearchReply`.
 
 Observability (the serving-smoke + chaos acceptance surface):
 
-  * ``serve.frontend.requests`` — submissions (attempted);
   * ``serve.admission.accepted / rejected / shed / deadline_expired``
     — every admission outcome, so overload behavior is countable;
   * ``serve.frontend.dispatches{qclass=B}`` — batches dispatched per
@@ -67,6 +66,13 @@ Observability (the serving-smoke + chaos acceptance surface):
   * ``serve.frontend.batch_occupancy`` — histogram of real (unpadded)
     batch sizes;
   * ``serve.frontend.latency_ms`` — submit→resolve latency histogram;
+  * ``serve.frontend.queue_wait_ms`` — submit→batch-taken wait in the
+    admission queue, one value per request;
+  * spans ``serve.frontend.wait`` (the dispatcher blocked on an empty
+    queue), ``serve.frontend.batch`` (take, expire, copy and pad) and
+    ``serve.frontend.dispatch`` (the index search). Profiler
+    annotations on the served path open on the dispatcher thread
+    only; the responder records histograms and opens no span;
   * ``serve.client.retries`` — client-side resubmissions.
 """
 from __future__ import annotations
@@ -268,7 +274,6 @@ class SearchFrontend:
         self._started = False
         self._stopping = False
         reg = obs.REGISTRY
-        self._c_requests = reg.counter("serve.frontend.requests")
         self._c_warmup = reg.counter("serve.frontend.warmup_dispatches")
         self._g_warmup_s = reg.gauge("serve.frontend.warmup_seconds")
         self._c_accepted = reg.counter("serve.admission.accepted")
@@ -284,6 +289,9 @@ class SearchFrontend:
         )
         self._h_latency = reg.histogram(
             "serve.frontend.latency_ms", unit="ms"
+        )
+        self._h_queue_wait = reg.histogram(
+            "serve.frontend.queue_wait_ms", unit="ms"
         )
 
     # -- lifecycle -----------------------------------------------------------
@@ -390,7 +398,6 @@ class SearchFrontend:
         now = time.perf_counter()
         deadline = None if deadline_s is None else now + float(deadline_s)
         fut: Future = Future()
-        self._c_requests.inc()
         try:
             shed = self._queue.put(_Request(v, fut, now, deadline))
         except OverloadError:
@@ -450,27 +457,32 @@ class SearchFrontend:
 
     def _dispatch_loop(self) -> None:
         while True:
-            first = self._queue.get()
+            with obs.span("serve.frontend.wait"):
+                first = self._queue.get()
             if first is _STOP:
                 return
-            batch = self._expire(self._take_batch(first))
-            if not batch:
-                continue
-            n = len(batch)
-            b_cls = next_pow2(n)
-            qarr = np.empty((b_cls, self.index.dim), np.float32)
-            for i, req in enumerate(batch):
-                qarr[i] = req.vec
-            qarr[n:] = batch[0].vec  # pad rows: answered, then dropped
+            with obs.span("serve.frontend.batch"):
+                batch = self._take_batch(first)
+                t_take = time.perf_counter()  # every request here was queued
+                batch = self._expire(batch)
+                if not batch:
+                    continue
+                n = len(batch)
+                b_cls = next_pow2(n)
+                qarr = np.empty((b_cls, self.index.dim), np.float32)
+                for i, req in enumerate(batch):
+                    qarr[i] = req.vec
+                qarr[n:] = batch[0].vec  # pad rows: answered, then dropped
             try:
-                res = self._search_batch(qarr)
+                with obs.span("serve.frontend.dispatch"):
+                    res = self._search_batch(qarr)
             except BaseException as e:  # fail the batch, keep serving
                 for req in batch:
                     req.future.set_exception(e)
                 continue
             self._c_dispatch[b_cls].inc()
             self._h_occupancy.observe(n)
-            self._respond.put((batch, res))
+            self._respond.put((batch, t_take, res))
 
     # -- responder -----------------------------------------------------------
     def _respond_loop(self) -> None:
@@ -478,7 +490,7 @@ class SearchFrontend:
             item = self._respond.get()
             if item is _STOP:
                 return
-            batch, res = item
+            batch, t_take, res = item
             # materialize once per batch (np.asarray is a no-op when the
             # index already returned host arrays), then slice per request
             gids = np.asarray(res.gids)
@@ -490,6 +502,7 @@ class SearchFrontend:
                     SearchReply(gids[i], dists[i], partial)
                 )
                 self._h_latency.observe((now - req.t_submit) * 1e3)
+                self._h_queue_wait.observe((t_take - req.t_submit) * 1e3)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -386,3 +386,123 @@ def test_obs_snapshot_includes_engine_and_index_series():
     assert any(
         k.startswith("span.engine.dispatch") for k in snap["histograms"]
     )
+
+
+# -- spans inside the engine dispatch, phase-2 fill, program builds -----------
+FUSED_CHILD_SPANS = (
+    "engine.phase1",
+    "engine.frontier_sync",
+    "engine.phase2",
+    "engine.certificate",
+    "engine.segment_merge",
+)
+
+
+def _span(name):
+    h = obs.REGISTRY.find(f"span.{name}")
+    return (h.count, h.sum) if h is not None else (0, 0.0)
+
+
+def test_fused_dispatch_child_spans_once_each():
+    """One quantized fused dispatch opens each step's child span exactly
+    once, inside `engine.dispatch`: their seconds sum to no more than
+    the parent's, and the f32 rerun runs only after a failed
+    certificate."""
+    rng = np.random.default_rng(21)
+    idx = StreamingIndex(
+        StreamingConfig(
+            dim=3, delta_capacity=64, spec=SPEC, storage_dtype="bfloat16"
+        )
+    )
+    idx.bulk_load(rng.standard_normal((300, 3)))
+    snap = idx.snapshot()
+    queries = rng.standard_normal((5, 3))
+    spec = QuerySpec(k=4, radius=1.0)
+    qengine.execute(snap, queries, spec)  # compile outside the count
+    obs.reset()
+    qengine.execute(snap, queries, spec)
+    assert _span("engine.dispatch")[0] == 1
+    for name in FUSED_CHILD_SPANS:
+        assert _span(name)[0] == 1, name
+    assert _span("engine.classic")[0] == 0
+    reruns = obs.REGISTRY.counter("quantized.rescore", result="fallback").value
+    assert _span("engine.f32_rerun")[0] == reruns
+    children = sum(
+        _span(n)[1] for n in FUSED_CHILD_SPANS + ("engine.f32_rerun",)
+    )
+    assert children <= _span("engine.dispatch")[1]
+    # the host copies of the merged answer sit inside engine.merge
+    assert _span("engine.readback")[0] == _span("engine.merge")[0] == 1
+
+
+def test_classic_dispatch_span(monkeypatch):
+    """The classic path (overflow fallback or the escape hatch) runs in
+    `engine.classic`, and phase 2 counts no slots for it."""
+    monkeypatch.setenv("REPRO_FUSED_TRAVERSAL", "0")
+    rng = np.random.default_rng(22)
+    idx = _small_index(rng, segments=1, delta=False)
+    qengine.execute(idx.snapshot(), rng.standard_normal((3, 3)),
+                    QuerySpec(k=2, radius=1.0))
+    assert _span("engine.classic")[0] == _span("engine.dispatch")[0] == 1
+    assert _span("engine.phase1")[0] == 0
+    for kind in ("frontier", "evaluated"):
+        assert obs.REGISTRY.counter("engine.phase2_slots", kind=kind).value == 0
+
+
+def test_phase2_slot_counters_match_host_frontier():
+    """`engine.phase2_slots{kind=frontier}` adds the real frontier
+    leaves (the host `leaf_frontier` oracle's sizes); `{kind=evaluated}`
+    the slots phase 2 evaluates, S_pow2 · Q · f_eff (3 same-class
+    segments stack to 4; f_eff is the pow2 class of the widest
+    frontier)."""
+    rng = np.random.default_rng(23)
+    idx = StreamingIndex(StreamingConfig(dim=3, delta_capacity=64, spec=SPEC))
+    for _ in range(3):
+        idx.bulk_load(rng.standard_normal((52, 3)))
+    snap = idx.snapshot()
+    groups = qengine.plan(snap)
+    assert [len(g.views) for g in groups] == [3]
+    queries = rng.standard_normal((6, 3))
+    k, r = 4, 1.5
+    widths = [
+        len(sh.leaf_frontier(seg.tree, q, k, r))
+        for seg in idx.segments
+        for q in queries
+    ]
+    f_eff = 1
+    while f_eff < max(widths):
+        f_eff *= 2
+    qengine.execute(snap, queries, QuerySpec(k=k, radius=r))
+    slots = {
+        kind: obs.REGISTRY.find("engine.phase2_slots", kind=kind).value
+        for kind in ("frontier", "evaluated")
+    }
+    assert slots == {"frontier": sum(widths),
+                     "evaluated": 4 * len(queries) * f_eff}
+
+
+def test_jit_build_seconds_by_function():
+    """A fresh jitted function, compiled once, adds one count under its
+    `fun` label to each of `jit.trace_s`, `jit.lower_s` and
+    `jit.compile_s`; a second call builds nothing and adds none."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def _obs_fresh_program(x):
+        return x * 3.0 + 1.0
+
+    def counts():
+        return [
+            getattr(obs.REGISTRY.find(n, fun="_obs_fresh_program"), "count", 0)
+            for n in ("jit.trace_s", "jit.lower_s", "jit.compile_s")
+        ]
+
+    x = jnp.arange(4.0)
+    _obs_fresh_program(x).block_until_ready()
+    assert counts() == [1, 1, 1]
+    for n in ("jit.trace_s", "jit.lower_s", "jit.compile_s"):
+        h = obs.REGISTRY.find(n, fun="_obs_fresh_program")
+        assert h.unit == "s" and h.sum >= 0.0
+    _obs_fresh_program(x).block_until_ready()
+    assert counts() == [1, 1, 1]

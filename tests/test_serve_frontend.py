@@ -101,6 +101,80 @@ def test_stop_drains_pending_requests(served_index):
         fe.submit(np.zeros(8, np.float32))
 
 
+class _Recorder:
+    """Forwards to a registry histogram and keeps each value, in order."""
+
+    def __init__(self, hist):
+        self.hist, self.values = hist, []
+
+    def observe(self, v):
+        self.values.append(v)
+        self.hist.observe(v)
+
+
+def test_queue_wait_one_value_per_request(served_index):
+    """N requests give N `serve.frontend.queue_wait_ms` values, each at
+    least 0 and at most that request's submit->resolve latency."""
+    fe = SearchFrontend(served_index, FrontendConfig(k=3, max_batch=8))
+    h = obs.REGISTRY.histogram("serve.frontend.queue_wait_ms", unit="ms")
+    count0 = h.count
+    wait, lat = _Recorder(fe._h_queue_wait), _Recorder(fe._h_latency)
+    fe._h_queue_wait, fe._h_latency = wait, lat
+    rng = np.random.default_rng(11)
+    n = 37
+    with fe:
+        futs = [
+            fe.submit(rng.normal(size=8).astype(np.float32)) for _ in range(n)
+        ]
+        for f in futs:
+            f.result(60)
+    assert h.count - count0 == n
+    assert len(wait.values) == len(lat.values) == n
+    for w, total in zip(wait.values, lat.values):
+        assert 0.0 <= w <= total
+
+
+def test_dispatch_span_per_dispatch(served_index, monkeypatch):
+    """The dispatcher's `serve.frontend.dispatch` span opens once per
+    batch it dispatches: its count equals the sum of
+    `serve.frontend.dispatches{qclass=*}`; warm-up dispatches, made
+    before the dispatcher starts, open none. While serving, profiler
+    annotations open on the dispatcher thread alone."""
+    cfg = FrontendConfig(k=4, max_batch=16)
+    span = obs.REGISTRY.histogram("span.serve.frontend.dispatch", unit="s")
+
+    def dispatched():
+        return sum(
+            obs.REGISTRY.counter(
+                "serve.frontend.dispatches", qclass=str(b)
+            ).value
+            for b in cfg.batch_classes
+        )
+
+    span0, disp0 = span.count, dispatched()
+    fe = SearchFrontend(served_index, cfg)
+    rng = np.random.default_rng(12)
+    annotating = set()
+    annotation = obs.trace._annotation
+
+    def spy(name):
+        annotating.add(threading.current_thread().name)
+        return annotation(name)
+
+    with fe:
+        monkeypatch.setattr(obs.trace, "_annotation", spy)
+        futs = [
+            fe.submit(rng.normal(size=8).astype(np.float32))
+            for _ in range(45)
+        ]
+        for f in futs:
+            f.result(60)
+    assert dispatched() - disp0 > 0
+    assert span.count - span0 == dispatched() - disp0
+    assert annotating == {"repro-serve-dispatch"}
+    for name in ("serve.frontend.wait", "serve.frontend.batch"):
+        assert obs.REGISTRY.find(f"span.{name}").count >= 1, name
+
 # -- per-request sampling keys (serve/engine.py) ------------------------------
 @pytest.fixture(scope="module")
 def tiny_engine():
